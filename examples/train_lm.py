@@ -81,7 +81,6 @@ def main() -> None:
 
         # cold-restart proof: a fresh trainer restores the newest manifest
         t2 = Trainer(run, pipe, ckpt)
-        t2.initialize()
         assert t2.restore_latest(), "no restorable checkpoint!"
         print(f"cold restore OK at step {t2.step}")
 

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_K = 512
 DEFAULT_BLOCK_N = 512
@@ -71,7 +69,7 @@ def gmm(lhs: jax.Array, rhs: jax.Array, tile_group_ids: jax.Array, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tile_group_ids, lhs, rhs)

@@ -1,6 +1,6 @@
 """Pallas TPU chunked selective-scan (Mamba-1 SSM).
 
-Grid = (B, di/block_d, S/chunk); the SSM state h [block_d, N] lives in VMEM
+Grid = (B, di/block_d, S/chunk); the SSM state h^T [N, block_d] lives in VMEM
 scratch across the sequential chunk axis, so the recurrence never round-trips
 HBM.  Within a chunk the recurrence is stepped with a fori_loop over VMEM
 tiles (the update is elementwise VPU work — there is no MXU contraction to
@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 DEFAULT_CHUNK = 64
 DEFAULT_BLOCK_D = 256
 
@@ -29,27 +27,37 @@ def _mamba_kernel(A_ref, dt_ref, b_ref, c_ref, x_ref, o_ref, h_scr, *,
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    A = A_ref[...].astype(jnp.float32)         # [bd, N]
-    dt = dt_ref[0].astype(jnp.float32)         # [C, bd]
-    b = b_ref[0].astype(jnp.float32)           # [C, N]
-    c = c_ref[0].astype(jnp.float32)           # [C, N]
-    x = x_ref[0].astype(jnp.float32)           # [C, bd]
+    # The state is kept transposed, h^T [N, bd], so that each step's dt/x
+    # rows [1, bd] broadcast over it without a relayout.  b, c and A turn
+    # to [N, *] once per chunk through an exact identity matmul (NT form).
+    N = A_ref.shape[1]
+    eye = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+                    == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1),
+                    1.0, 0.0)
 
-    def step(t, carry):
-        h, ys = carry
-        dt_t = dt[t]                           # [bd]
-        dA = jnp.exp(dt_t[:, None] * A)        # [bd, N]
-        dBx = (dt_t * x[t])[:, None] * b[t][None, :]
-        h = dA * h + dBx
-        y = jnp.sum(h * c[t][None, :], axis=-1)          # [bd]
-        ys = jax.lax.dynamic_update_slice_in_dim(ys, y[None], t, 0)
-        return h, ys
+    def to_cols(m):                            # [R, N] -> [N, R]
+        return jax.lax.dot_general(eye, m.astype(jnp.float32),
+                                   (((1,), (1,)), ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
 
-    h0 = h_scr[...]
-    ys0 = jnp.zeros((chunk, dt.shape[1]), jnp.float32)
-    hT, ys = jax.lax.fori_loop(0, chunk, step, (h0, ys0))
-    h_scr[...] = hT
-    o_ref[0] = ys.astype(o_ref.dtype)
+    At = to_cols(A_ref[...])                   # [N, bd]
+    bt = to_cols(b_ref[0])                     # [N, C]
+    ct = to_cols(c_ref[0])                     # [N, C]
+    lane = jax.lax.broadcasted_iota(jnp.int32, bt.shape, 1)
+
+    def step(t, h):
+        dt_t = dt_ref[0, pl.ds(t, 1), :].astype(jnp.float32)   # [1, bd]
+        x_t = x_ref[0, pl.ds(t, 1), :].astype(jnp.float32)     # [1, bd]
+        sel = lane == t
+        b_t = jnp.sum(jnp.where(sel, bt, 0.0), axis=1, keepdims=True)
+        c_t = jnp.sum(jnp.where(sel, ct, 0.0), axis=1, keepdims=True)
+        h = jnp.exp(dt_t * At) * h + b_t * (dt_t * x_t)          # [N, bd]
+        y = jnp.sum(h * c_t, axis=0, keepdims=True)              # [1, bd]
+        o_ref[0, pl.ds(t, 1), :] = y.astype(o_ref.dtype)
+        return h
+
+    h_scr[...] = jax.lax.fori_loop(0, chunk, step, h_scr[...])
 
 
 def mamba_scan(A: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
@@ -80,8 +88,8 @@ def mamba_scan(A: jax.Array, dt: jax.Array, b: jax.Array, c: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, block_d),
                                lambda bi, di_, ci: (bi, ci, di_)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((N, block_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(A, dt, b, c, x)

@@ -1,8 +1,8 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Jit'd public wrappers for the Pallas kernels (model layout in and out).
 
-On a CPU backend (this container) kernels run in ``interpret=True`` mode —
-the kernel body executes as jnp ops per grid cell, which validates the
-tiling/masking logic exactly.  On TPU the same call sites compile to Mosaic.
+The kernels compile to Mosaic for the TPU.  On any other backend a call
+raises unless its caller asked for interpret mode, e.g. under
+``pltpu.force_tpu_interpret_mode()``; nothing here chooses it for them.
 """
 from __future__ import annotations
 
@@ -17,10 +17,6 @@ from repro.kernels import mamba_scan as _mb
 from repro.kernels import gmm as _gmm
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "q_offset",
                                              "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
@@ -30,8 +26,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     o = _fa.flash_attention(qt, kt, vt, causal=causal, q_offset=q_offset,
-                            block_q=block_q, block_k=block_k,
-                            interpret=_interpret_default())
+                            block_q=block_q, block_k=block_k)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -39,8 +34,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 def rwkv6_scan(r, k, v, w, u, *, chunk: int = _rw.DEFAULT_CHUNK):
     """r,k,v,w: [B,S,H,D]; u: [H,D] -> [B,S,H,D] (model layout)."""
     tr = lambda t: jnp.swapaxes(t, 1, 2)
-    o = _rw.rwkv6_scan(tr(r), tr(k), tr(v), tr(w), u, chunk=chunk,
-                       interpret=_interpret_default())
+    o = _rw.rwkv6_scan(tr(r), tr(k), tr(v), tr(w), u, chunk=chunk)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -48,8 +42,7 @@ def rwkv6_scan(r, k, v, w, u, *, chunk: int = _rw.DEFAULT_CHUNK):
 def mamba_scan(A, dt, b, c, x, *, chunk: int = _mb.DEFAULT_CHUNK,
                block_d: int = _mb.DEFAULT_BLOCK_D):
     """A: [di,N]; dt,x: [B,S,di]; b,c: [B,S,N] -> y [B,S,di]."""
-    return _mb.mamba_scan(A, dt, b, c, x, chunk=chunk, block_d=block_d,
-                          interpret=_interpret_default())
+    return _mb.mamba_scan(A, dt, b, c, x, chunk=chunk, block_d=block_d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k", "block_n"))
@@ -58,8 +51,7 @@ def gmm_padded(lhs, rhs, tile_group_ids, *,
                block_k: int = _gmm.DEFAULT_BLOCK_K,
                block_n: int = _gmm.DEFAULT_BLOCK_N):
     return _gmm.gmm(lhs, rhs, tile_group_ids, block_m=block_m,
-                    block_k=block_k, block_n=block_n,
-                    interpret=_interpret_default())
+                    block_k=block_k, block_n=block_n)
 
 
 def gmm_sorted(lhs, rhs, group_sizes, *, block_m: int = _gmm.DEFAULT_BLOCK_M):

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 DEFAULT_CHUNK = 64
 
 
@@ -44,7 +42,13 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
     S0 = s_scr[...]                           # [D, D]
 
     logw = jnp.log(jnp.maximum(w, 1e-37))
-    L = jnp.cumsum(logw, axis=0)              # [C, D]  (= L_t)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # prefix sum as a lower-triangular-ones matmul (Mosaic has no cumsum)
+    L = jax.lax.dot_general(jnp.where(row >= col, 1.0, 0.0), logw,
+                            (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # [C, D]
     L_prev = L - logw                         # [C, D]  (= L_{t-1})
 
     # inter-chunk: (r ⊙ e^{L_prev}) @ S0
@@ -54,13 +58,13 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
 
     # intra-chunk: A[t,s] = Σ_d r[t,d] k[s,d] e^{L_prev[t,d]-L[s,d]} (s<t)
     expo = L_prev[:, None, :] - L[None, :, :]            # [C, C, D]
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-           > jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-    gated = jnp.where(tri[:, :, None], jnp.exp(expo), 0.0)
-    A = jnp.einsum("td,sd,tsd->ts", r, k, gated)
+    t3 = jax.lax.broadcasted_iota(jnp.int32, expo.shape, 0)
+    s3 = jax.lax.broadcasted_iota(jnp.int32, expo.shape, 1)
+    gated = jnp.where(t3 > s3, jnp.exp(expo), 0.0)
+    A = jnp.sum(r[:, None, :] * k[None, :, :] * gated, axis=-1)
     # diagonal bonus: r_t · (u ⊙ k_t)
-    diag = jnp.sum(r * u * k, axis=-1)                    # [C]
-    A = A + jnp.diag(diag)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)     # [C, 1]
+    A = A + jnp.where(row == col, diag, 0.0)
     y = y + jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
@@ -100,7 +104,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, D), x_map),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(resh(r), resh(k), resh(v), resh(w), ur)

@@ -1,8 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
-# NOTE: the two lines above MUST run before any other import (jax locks the
-# device count on first init).  Everything below is ordinary code.
+# A CPU-only compile tool: neither this process nor its per-cell children
+# may take an attached TPU, which belongs to one process at a time.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# NOTE: the lines above MUST run before any other import (jax locks the
+# platform and device count on first init).  Everything below is ordinary
+# code.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -60,6 +64,9 @@ ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 # latency-friendly TP-only decode layout.
 BIG_ARCHS = {"jamba-1.5-large-398b", "qwen2-vl-72b", "dbrx-132b",
              "qwen2.5-32b", "qwen3-moe-30b-a3b", "phi3-medium-14b"}
+
+# The chip the production meshes stand for (its peaks price the roofline).
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _cell_run_config(arch: str, shape_name: str, *, policy: str,
@@ -182,7 +189,8 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str, *,
     flops_dev = float(loopaware["flops"])
     bytes_dev = float(loopaware["traffic_bytes"])
     coll_dev = float(loopaware["collective_total"])
-    terms = roofline_terms(flops_dev, bytes_dev, coll_dev)
+    terms = roofline_terms(flops_dev, bytes_dev, coll_dev,
+                           device_kind=TARGET_DEVICE_KIND)
     mem_fields = {}
     for f in ("temp_size_in_bytes", "argument_size_in_bytes",
               "output_size_in_bytes", "alias_size_in_bytes",

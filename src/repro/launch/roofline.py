@@ -1,22 +1,24 @@
 """Roofline-term derivation from a compiled dry-run artifact.
 
-TPU v5e single-chip constants (targets; the container only compiles):
-  peak bf16 compute 197 TFLOP/s, HBM BW 819 GB/s, ICI ~50 GB/s/link.
-
     compute term    = HLO_FLOPs / peak            (cost_analysis, per device)
     memory term     = HLO_bytes / HBM_bw
     collective term = collective_bytes / link_bw  (parsed from HLO text)
 
-The dominant term is the structural bottleneck the §Perf loop iterates on.
+The peaks come from :data:`PEAKS`, keyed by ``device_kind`` as JAX reports
+it; a kind that is not in the table is an error, never a default.  The
+dominant term is the structural bottleneck the §Perf loop iterates on.
 """
 from __future__ import annotations
 
 import re
 from typing import Dict, Tuple
 
-PEAK_FLOPS = 197e12         # bf16 FLOP/s per chip
-HBM_BW = 819e9              # bytes/s per chip
-ICI_BW = 50e9               # bytes/s per link
+#: Published per-chip peaks (Google Cloud documentation, "TPU v5e"):
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+#: interconnect over four links (50 GB/s each).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -70,10 +72,15 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 
 def roofline_terms(flops_per_device: float, bytes_per_device: float,
-                   coll_bytes_per_device: float) -> Dict[str, float]:
-    t_c = flops_per_device / PEAK_FLOPS
-    t_m = bytes_per_device / HBM_BW
-    t_x = coll_bytes_per_device / ICI_BW
+                   coll_bytes_per_device: float, *, device_kind: str,
+                   ) -> Dict[str, float]:
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}")
+    peak = PEAKS[device_kind]
+    t_c = flops_per_device / peak["flops"]
+    t_m = bytes_per_device / peak["hbm_bw"]
+    t_x = coll_bytes_per_device / peak["ici_bw"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])[0]
     total = max(t_c, t_m, t_x)

@@ -16,11 +16,13 @@ import jax
 from repro.configs import ARCH_IDS, get_config, get_tiny_config
 from repro.core import Fabric, FabricSpec, SiteSpec
 from repro.checkpoint import CheckpointManager
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serve.engine import ServeEngine, Request
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen3-8b")
     ap.add_argument("--tiny", action="store_true")
@@ -41,12 +43,12 @@ def main() -> None:
     net = fabric.network
     s = fabric.login("server")
 
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    init = jax.jit(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     mgr = CheckpointManager(s.client, f"home/models/{cfg.name}")
-    mgr.save(0, {"params": params})
+    mgr.save(0, {"params": init()})     # the published copy is not kept
     s.client.sync()
     clock0 = net.clock
-    restored, _ = mgr.restore({"params": params})
+    restored, _ = mgr.restore({"params": jax.eval_shape(init)})
     print(f"weights restored through XUFS in {net.clock - clock0:.2f}s WAN")
 
     engine = ServeEngine(cfg, restored["params"], slots=args.slots,
@@ -62,8 +64,11 @@ def main() -> None:
         engine.step()
         ticks += 1
     dt = time.perf_counter() - t0
+    dev = jax.devices()[0]
     print(f"{args.requests} requests, {engine.tokens_generated} tokens, "
-          f"{ticks} ticks, {engine.tokens_generated / dt:.1f} tok/s (CPU)")
+          f"{ticks} ticks, {engine.tokens_generated / dt:.1f} tok/s "
+          f"({dev.platform}: {dev.device_kind} x{jax.device_count()}, "
+          f"compilation included)")
 
 
 if __name__ == "__main__":

@@ -18,10 +18,12 @@ from repro.configs import ARCH_IDS, get_config, get_tiny_config
 from repro.core import Fabric, FabricSpec, MountSpec, SiteSpec
 from repro.checkpoint import CheckpointManager
 from repro.data.pipeline import SyntheticCorpus, DataPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import Trainer, FaultMonitor, FaultEvent
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen3-4b")
     ap.add_argument("--tiny", action="store_true",

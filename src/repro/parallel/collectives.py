@@ -15,21 +15,13 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis: str) -> int:
-    """``lax.axis_size`` only exists on newer jax; a psum of ones is the
-    portable spelling (constant-folded, never hits the wire)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def hierarchical_psum(x: jax.Array, pod_axis: str, inner_axis: str,
                       ) -> jax.Array:
     """psum over (pod_axis, inner_axis) with pod traffic minimized.
 
     Requires x's leading dim divisible by the inner axis size.
     """
-    n_inner = _axis_size(inner_axis)
+    n_inner = lax.axis_size(inner_axis)
     lead = x.shape[0]
     if lead % n_inner != 0:
         # fall back: flat psum (correct, just not bandwidth-optimal)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.config.base import ModelConfig
 
@@ -124,12 +123,12 @@ def ep_moe_apply(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array,
         return out.reshape(x_loc.shape)
 
     pspec_x = P(batch_axes, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pspec_x, P(None, None), P(tp_axis, None, None),
                   P(tp_axis, None, None), P(tp_axis, None, None)),
         out_specs=pspec_x,
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wi_gate"], params["wi_up"],
       params["wo"])
     return out

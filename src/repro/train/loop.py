@@ -45,16 +45,20 @@ class Trainer:
         self.monitor = monitor or FaultMonitor(n_workers=1)
         self.ckpt_every = ckpt_every
         self.pump_ops_per_step = pump_ops_per_step
-        self.step_fn = jax.jit(make_train_step(run))
+        # the step consumes the state it replaces: one live copy on device
+        self.step_fn = jax.jit(make_train_step(run), donate_argnums=(0, 1))
         self.params: Any = None
         self.opt_state: Any = None
         self.step = 0
 
     # ---- state ------------------------------------------------------------
+    def _init_state(self) -> Dict[str, Any]:
+        params = init_params(self.run.model, jax.random.PRNGKey(self.run.seed))
+        return {"params": params, "opt": make_opt_state(self.run, params)}
+
     def initialize(self) -> None:
-        key = jax.random.PRNGKey(self.run.seed)
-        self.params = init_params(self.run.model, key)
-        self.opt_state = make_opt_state(self.run, self.params)
+        state = jax.jit(self._init_state)()
+        self.params, self.opt_state = state["params"], state["opt"]
         self.step = 0
 
     def _state_tree(self) -> Dict[str, Any]:
@@ -65,10 +69,15 @@ class Trainer:
                        extra={"data": self.pipeline.state()})
 
     def restore_latest(self) -> bool:
-        """Post-crash: replay the WAL, then restore the newest manifest."""
+        """Post-crash: replay the WAL, then restore the newest manifest.
+
+        The live state is dropped first and the restore is shaped by a
+        template of shapes, so the device never holds two copies."""
         self.ckpt.client.sync()
+        self.params = self.opt_state = None
         try:
-            tree, manifest = self.ckpt.restore(self._state_tree())
+            tree, manifest = self.ckpt.restore(
+                jax.eval_shape(self._init_state))
         except FileNotFoundError:
             return False
         self.params = tree["params"]

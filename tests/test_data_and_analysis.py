@@ -129,10 +129,16 @@ def test_collective_bytes_flat_parser():
 
 
 def test_roofline_dominant_term():
-    t = roofline_terms(197e12, 819e9 * 2, 0.0)   # 1s compute, 2s memory
+    t = roofline_terms(197e12, 819e9 * 2, 0.0,    # 1s compute, 2s memory
+                       device_kind="TPU v5 lite")
     assert t["dominant"] == "memory"
     assert abs(t["compute_s"] - 1.0) < 1e-9
     assert t["roofline_fraction_compute"] == pytest.approx(0.5)
+
+
+def test_roofline_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="cpu"):
+        roofline_terms(1.0, 1.0, 0.0, device_kind="cpu")
 
 
 def test_model_flops_train_vs_serve():

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
@@ -44,6 +45,7 @@ def test_flash_attention_sweep(B, Hq, Hkv, Sq, Skv, D, dtype, causal):
     (1, 2, 64, 32, 16),
     (2, 3, 128, 64, 64),
     (1, 1, 256, 64, 32),
+    (1, 2, 256, 64, 64),           # rwkv6-3b head width, default chunk
 ])
 def test_rwkv6_scan_sweep(B, H, S, D, chunk):
     ks = jax.random.split(KEY, 5)
@@ -81,6 +83,7 @@ def test_rwkv6_strong_decay_numerics():
     (1, 64, 32, 8, 16, 32),
     (2, 128, 64, 16, 64, 32),
     (1, 256, 128, 16, 32, 64),
+    (1, 128, 512, 16, 64, 256),    # jamba d_state, default block_d/chunk
 ])
 def test_mamba_scan_sweep(B, S, di, N, chunk, block_d):
     ks = jax.random.split(KEY, 5)
@@ -107,7 +110,8 @@ def test_gmm_sweep(sizes):
     ks = jax.random.split(KEY, 2)
     lhs = jax.random.normal(ks[0], (M, K), jnp.float32)
     rhs = jax.random.normal(ks[1], (G, K, N), jnp.float32)
-    out = ops.gmm_sorted(lhs, rhs, np.asarray(sizes), block_m=128)
+    with pltpu.force_tpu_interpret_mode():
+        out = ops.gmm_sorted(lhs, rhs, np.asarray(sizes), block_m=128)
     expect = ref.gmm_ref(lhs, rhs, jnp.asarray(sizes))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
@@ -122,7 +126,15 @@ def test_flash_matches_model_xla_path():
     p = init_params(cfg, KEY)
     batch = make_batch(cfg, 2, 128)
     lo_x, _ = forward(cfg.replace(attention_impl="xla"), p, batch)
-    lo_k, _ = forward(cfg.replace(attention_impl="pallas"), p, batch)
+    with pltpu.force_tpu_interpret_mode():
+        lo_k, _ = forward(cfg.replace(attention_impl="pallas"), p, batch)
     np.testing.assert_allclose(np.asarray(lo_x, np.float32),
                                np.asarray(lo_k, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+def test_kernel_off_tpu_raises_unless_interpret_asked():
+    """No silent fallback: off the TPU a kernel runs only when asked to."""
+    q =jnp.zeros((1, 128, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        ops.flash_attention(q, q, q)

@@ -139,7 +139,7 @@ def test_hierarchical_psum_and_compressed_psum():
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_test_mesh
         from repro.parallel.collectives import hierarchical_psum, compressed_psum
 
