@@ -1,0 +1,12 @@
+"""Model operations of the window's steps over the window (its saves
+included) and the chips' bf16 peak, in percent (recomputation not
+counted), in the checkpoint cell."""
+from bench.peaks import peak
+
+
+def read(rec):
+    if rec["platform"] != "tpu":
+        return None     # no chip, no share of its peak
+    ops = rec["steps"] * rec["step_flops"]
+    return 100.0 * ops / (rec["window_s"] * rec["chips"]
+                          * peak(rec["device_kind"], "bf16_flops"))
