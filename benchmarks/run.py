@@ -28,14 +28,14 @@ def main(argv=None) -> int:
         table1_sizes, fig23_iozone, fig4_build, fig5_largefile,
         fig_replica_read, fig_quorum_write, fig_congestion,
         fig_maintenance, fig_conflict, fig_eviction, fig_bulk,
-        sharing_census, roofline,
+        sharing_census,
     )
 
     rc = 0
     for mod in (table1_sizes, fig23_iozone, fig4_build, fig5_largefile,
                 fig_replica_read, fig_quorum_write, fig_congestion,
                 fig_maintenance, fig_conflict, fig_eviction, fig_bulk,
-                sharing_census, roofline):
+                sharing_census):
         rc |= int(mod.run(smoke=args.smoke) or 0)
     return rc
 

@@ -1,8 +1,10 @@
 """Striped, write-behind checkpointing over the XUFS fabric.
 
-Save path (async, never blocks the train step on the WAN):
-  1. every leaf tensor is serialized and ``close()``d through the
-     XufsClient -> one aggregated store op per leaf in the WAL;
+Save path (runs on the caller's thread: the step waits for it; only the
+WAL's drain toward home comes later, as ``client.pump()`` ticks or
+``client.sync()``):
+  1. every leaf tensor is pulled to the host, serialized and ``close()``d
+     through the XufsClient -> one aggregated store op per leaf in the WAL;
   2. a manifest (leaf paths, shapes, dtypes, step) is written AFTER all
      leaves — WAL FIFO order guarantees the manifest reaches home only
      once every leaf it references is durable (**last-close-wins commit**);
@@ -23,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.namespace import XufsClient
 
 Params = Any
@@ -70,15 +73,24 @@ class CheckpointManager:
     # ---- save -----------------------------------------------------------
     def save(self, step: int, tree: Params, *,
              extra: Optional[Dict] = None) -> str:
+        with obs.span("ckpt.save"):
+            return self._save(step, tree, extra)
+
+    def _save(self, step: int, tree: Params, extra: Optional[Dict]) -> str:
         base = f"{self.prefix}/step_{step:08d}"
         manifest: Dict[str, Any] = {"step": step, "leaves": [],
                                     "extra": extra or {}}
         for path, leaf in _leaf_paths(tree):
             name = _path_str(path)
             obj = f"{base}/{name}.npy"
-            arr = np.asarray(leaf)
+            with obs.span("ckpt.pull"):
+                arr = np.asarray(leaf)
+            obs.count("ckpt.bytes", arr.nbytes)
+            with obs.span("ckpt.encode"):
+                data = _encode(arr)
             with self.client.open(obj, "w") as f:
-                f.write(_encode(arr))
+                f.write(data)
+                del data      # freed before close() copies the buffer
             manifest["leaves"].append(
                 {"name": name, "path": obj, "shape": list(arr.shape),
                  "dtype": str(arr.dtype)})

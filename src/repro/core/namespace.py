@@ -21,6 +21,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core.cache import (
     CacheSpace, CacheEntry, EMPTY, VALID, DIRTY, INVALID,
 )
@@ -79,13 +80,14 @@ class XufsFile:
         return out
 
     def write(self, data: bytes) -> int:
-        end = self._pos + len(data)
-        if end > len(self._buf):
-            self._buf.extend(b"\x00" * (end - len(self._buf)))
-        self._buf[self._pos:end] = data
-        self._pos = end
-        self._dirty = True
-        return len(data)
+        with obs.span("xufs.write"):
+            end = self._pos + len(data)
+            if end > len(self._buf):
+                self._buf.extend(b"\x00" * (end - len(self._buf)))
+            self._buf[self._pos:end] = data
+            self._pos = end
+            self._dirty = True
+            return len(data)
 
     def seek(self, pos: int) -> None:
         self._pos = pos
@@ -96,7 +98,8 @@ class XufsFile:
             return
         self.closed = True
         if self._dirty:
-            self.client._close_write(self.path, bytes(self._buf))
+            with obs.span("xufs.close"):
+                self.client._close_write(self.path, bytes(self._buf))
 
     def __enter__(self):
         return self
@@ -268,7 +271,8 @@ class XufsClient:
         prev = self.cache.lookup(path)
         if prev is not None:
             st.version = prev.stat.version
-        self.cache.store_data(path, data, st, state=DIRTY)
+        with obs.span("xufs.cache_store"):
+            self.cache.store_data(path, data, st, state=DIRTY)
         if not m.is_localized(path):
             self.oplog.append("store", path, data)
 

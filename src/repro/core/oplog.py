@@ -29,6 +29,7 @@ import os
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core.transport import DisconnectedError
 
 PENDING = "pending"
@@ -132,6 +133,10 @@ class MetaOpQueue:
 
     def append(self, op: str, path: str,
                data: Optional[bytes] = None) -> OpRecord:
+        with obs.span("wal.append"):
+            return self._append(op, path, data)
+
+    def _append(self, op: str, path: str, data: Optional[bytes]) -> OpRecord:
         seq = self._next_seq
         self._next_seq += 1
         payload_file = None
@@ -257,6 +262,12 @@ class MetaOpQueue:
         missed quorum subclasses) stops the drain; partial acks stay
         persisted.  Returns the number of client-complete ops.
         """
+        with obs.span("wal.flush"):
+            return self._flush(apply_fn, max_ops)
+
+    def _flush(self, apply_fn: Callable[[OpRecord, Optional[bytes]],
+                                        Optional[bool]],
+               max_ops: Optional[int]) -> int:
         done = 0
         parked_paths = {r.path for r in self.unreconciled()}
         for rec in self.pending():

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config.base import ModelConfig
 from repro.core.namespace import XufsClient
 from repro.data.batches import batch_shapes
@@ -87,7 +88,6 @@ class DataPipeline:
         self.read_ahead = read_ahead
         self._shard_cache: Dict[int, np.ndarray] = {}
         self._cursor = 0          # global token cursor
-        self.stalls = 0
 
     # ---- shard access ------------------------------------------------------
     def _load_shard(self, i: int) -> np.ndarray:
@@ -128,28 +128,30 @@ class DataPipeline:
         shapes = batch_shapes(self.cfg, self.batch, self.seq)
         toks_shape = shapes["tokens"][0]
         n = int(np.prod(toks_shape)) + 1
-        flat = self._take(n)
-        tokens = flat[:-1].reshape(toks_shape)
-        targets = np.concatenate([flat[1:]]).reshape(-1)[
-            : int(np.prod(shapes["targets"][0]))].reshape(
-            shapes["targets"][0])
-        out: Dict[str, jax.Array] = {
-            "tokens": jnp.asarray(tokens),
-            "targets": jnp.asarray(targets),
-        }
-        pshape, _ = shapes["positions"]
-        if len(pshape) == 3:   # VLM [3, B, S]
-            pos = np.broadcast_to(np.arange(pshape[-1], dtype=np.int32),
-                                  pshape[1:])
-            out["positions"] = jnp.asarray(np.broadcast_to(pos, pshape))
-        else:
-            out["positions"] = jnp.asarray(np.broadcast_to(
-                np.arange(pshape[-1], dtype=np.int32)[None], pshape))
-        if "frontend" in shapes:
-            fshape, fdtype = shapes["frontend"]
-            rng = np.random.default_rng(self._cursor)
-            out["frontend"] = jnp.asarray(
-                rng.standard_normal(fshape, dtype=np.float32)).astype(fdtype)
+        with obs.span("pipeline.read"):
+            flat = self._take(n)
+            tokens = flat[:-1].reshape(toks_shape)
+            targets = np.concatenate([flat[1:]]).reshape(-1)[
+                : int(np.prod(shapes["targets"][0]))].reshape(
+                shapes["targets"][0])
+        with obs.span("pipeline.to_device"):
+            out: Dict[str, jax.Array] = {
+                "tokens": jnp.asarray(tokens),
+                "targets": jnp.asarray(targets),
+            }
+            pshape, _ = shapes["positions"]
+            if len(pshape) == 3:   # VLM [3, B, S]
+                pos = np.broadcast_to(
+                    np.arange(pshape[-1], dtype=np.int32), pshape[1:])
+                out["positions"] = jnp.asarray(np.broadcast_to(pos, pshape))
+            else:
+                out["positions"] = jnp.asarray(np.broadcast_to(
+                    np.arange(pshape[-1], dtype=np.int32)[None], pshape))
+            if "frontend" in shapes:
+                fshape, fdtype = shapes["frontend"]
+                rng = np.random.default_rng(self._cursor)
+                out["frontend"] = jnp.asarray(rng.standard_normal(
+                    fshape, dtype=np.float32)).astype(fdtype)
         return out
 
     def __iter__(self) -> Iterator[Dict[str, jax.Array]]:

@@ -19,8 +19,7 @@ lowers the jitted step, compiles it, and records:
   * cost_analysis()    — per-device FLOPs / bytes for §Roofline;
   * collective op bytes parsed from the post-SPMD HLO text.
 
-Artifacts land in experiments/artifacts/<arch>__<shape>__<mesh>.json and
-are consumed by benchmarks/roofline.py and EXPERIMENTS.md.
+Artifacts land in experiments/artifacts/<arch>__<shape>__<mesh>.json.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k --mesh single

@@ -187,11 +187,12 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array,
                     kv_positions: Optional[jax.Array] = None) -> jax.Array:
     """Full (train/prefill) attention.  x: [B,S,d] -> [B,S,d]."""
     dt = jnp.dtype(cfg.dtype)
-    q, k, v = _project_qkv(cfg, p, x, positions, kv_x, kv_positions)
-    o = _attend(cfg, q, k, v, causal=causal)
-    B, S = x.shape[:2]
-    o = o.reshape(B, S, cfg.q_dim)
-    return jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(dt))
+    with jax.named_scope("attention"):
+        q, k, v = _project_qkv(cfg, p, x, positions, kv_x, kv_positions)
+        o = _attend(cfg, q, k, v, causal=causal)
+        B, S = x.shape[:2]
+        o = o.reshape(B, S, cfg.q_dim)
+        return jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(dt))
 
 
 def attention_prefill(cfg: ModelConfig, p: Params, x: jax.Array,

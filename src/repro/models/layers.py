@@ -138,10 +138,11 @@ def mlp_axes(cfg: ModelConfig) -> Axes:
 
 def mlp_apply(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     dt = jnp.dtype(cfg.dtype)
-    gate = jnp.einsum("...d,df->...f", x, p["wi_gate"].astype(dt))
-    up = jnp.einsum("...d,df->...f", x, p["wi_up"].astype(dt))
-    return jnp.einsum("...f,fd->...d", jax.nn.silu(gate) * up,
-                      p["wo"].astype(dt))
+    with jax.named_scope("mlp"):
+        gate = jnp.einsum("...d,df->...f", x, p["wi_gate"].astype(dt))
+        up = jnp.einsum("...d,df->...f", x, p["wi_up"].astype(dt))
+        return jnp.einsum("...f,fd->...d", jax.nn.silu(gate) * up,
+                          p["wo"].astype(dt))
 
 
 # ---------------------------------------------------------------------------

@@ -150,9 +150,10 @@ def _embed_inputs(cfg: ModelConfig, p: Params, batch: Batch) -> jax.Array:
 
 
 def _logits(cfg: ModelConfig, p: Params, h: jax.Array) -> jax.Array:
-    h = L.rmsnorm(h, p["final_norm"], cfg.rms_eps)
-    logits = L.unembed(cfg, p["embed"], h)
-    return shard(logits, "batch", None, "vocab_act")
+    with jax.named_scope("head"):
+        h = L.rmsnorm(h, p["final_norm"], cfg.rms_eps)
+        logits = L.unembed(cfg, p["embed"], h)
+        return shard(logits, "batch", None, "vocab_act")
 
 
 # ---------------------------------------------------------------------------
@@ -227,30 +228,35 @@ Z_LOSS_COEF = 1e-4
 def loss_fn(cfg: ModelConfig, p: Params, batch: Batch,
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     logits, aux = forward(cfg, p, batch)
-    targets = batch["targets"]
-    V = cfg.vocab_size
-    if cfg.frontend_embed_dim and "frontend" in batch and cfg.family != ENCDEC:
-        # frontend positions carry no next-token target; score text tail only
-        S_text = targets.shape[1]
-        logits = logits[:, -S_text:, :]
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    mask = (targets >= 0).astype(jnp.float32)
-    tgt = jnp.where(targets >= 0, targets, 0)
-    # target log-prob via a one-hot masked reduction rather than a gather:
-    # GSPMD partitions select+reduce along the (model-sharded) vocab dim,
-    # while a take_along_axis gather forces an involuntary all-gather of
-    # the [B,S,V] logits on every device (measured +10 GB/device on the
-    # 152k-vocab archs — see EXPERIMENTS.md §Perf iteration 1).
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
-    ll = jnp.sum(jnp.where(vocab_iota == tgt[..., None], lf, 0.0), axis=-1)
-    nll = (lse - ll) * mask
-    denom = jnp.maximum(jnp.sum(mask), 1.0)
-    ce = jnp.sum(nll) / denom
-    z = Z_LOSS_COEF * jnp.sum(jnp.square(lse) * mask) / denom
-    total = ce + z + aux
-    return total, {"loss": total, "ce": ce, "aux": aux, "z": z,
-                   "tokens": jnp.sum(mask)}
+    with jax.named_scope("head"):      # the loss; _logits holds the rest
+        targets = batch["targets"]
+        V = cfg.vocab_size
+        if (cfg.frontend_embed_dim and "frontend" in batch
+                and cfg.family != ENCDEC):
+            # frontend positions carry no next-token target; score the text
+            # tail only
+            S_text = targets.shape[1]
+            logits = logits[:, -S_text:, :]
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        mask = (targets >= 0).astype(jnp.float32)
+        tgt = jnp.where(targets >= 0, targets, 0)
+        # target log-prob via a one-hot masked reduction rather than a gather:
+        # GSPMD partitions select+reduce along the (model-sharded) vocab dim,
+        # while a take_along_axis gather forces an involuntary all-gather of
+        # the [B,S,V] logits on every device (measured +10 GB/device on the
+        # 152k-vocab archs — see EXPERIMENTS.md §Perf iteration 1).
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape,
+                                              lf.ndim - 1)
+        ll = jnp.sum(jnp.where(vocab_iota == tgt[..., None], lf, 0.0),
+                     axis=-1)
+        nll = (lse - ll) * mask
+        denom = jnp.maximum(jnp.sum(mask), 1.0)
+        ce = jnp.sum(nll) / denom
+        z = Z_LOSS_COEF * jnp.sum(jnp.square(lse) * mask) / denom
+        total = ce + z + aux
+        return total, {"loss": total, "ce": ce, "aux": aux, "z": z,
+                       "tokens": jnp.sum(mask)}
 
 
 # ---------------------------------------------------------------------------

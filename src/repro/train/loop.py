@@ -17,6 +17,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.config.base import RunConfig
 from repro.data.pipeline import DataPipeline
@@ -104,9 +105,11 @@ class Trainer:
                     self.initialize()
                 continue
             batch = self.pipeline.next_batch()
-            self.params, self.opt_state, metrics = self.step_fn(
-                self.params, self.opt_state, batch)
-            losses.append(float(metrics["loss"]))
+            with obs.span("train.dispatch"):
+                self.params, self.opt_state, metrics = self.step_fn(
+                    self.params, self.opt_state, batch)
+            with obs.span("train.loss_sync"):
+                losses.append(float(metrics["loss"]))
             self.step += 1
             # write-behind: drain a few WAL ops toward home per step
             self.ckpt.client.pump(max_ops=self.pump_ops_per_step)

@@ -73,17 +73,18 @@ def make_train_step(run: RunConfig) -> Callable:
             loss = loss / n_micro
             metrics = jax.tree.map(lambda x: x[-1], ms)
 
-        grads, gnorm = clip_by_global_norm(grads, run.optim.grad_clip)
-        new_ef = None
-        if run.optim.grad_compress == "int8":
-            grads, new_ef = compress_decompress(grads,
-                                                opt_state["ef_error"])
-        lr = lr_at(opt_state["count"], run.optim)
-        core_state = {k: opt_state[k] for k in ("m", "v", "count")}
-        new_params, new_state = adamw_update(grads, core_state, params, lr,
-                                             run.optim)
-        if new_ef is not None:
-            new_state["ef_error"] = new_ef
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, run.optim.grad_clip)
+            new_ef = None
+            if run.optim.grad_compress == "int8":
+                grads, new_ef = compress_decompress(grads,
+                                                    opt_state["ef_error"])
+            lr = lr_at(opt_state["count"], run.optim)
+            core_state = {k: opt_state[k] for k in ("m", "v", "count")}
+            new_params, new_state = adamw_update(grads, core_state, params,
+                                                 lr, run.optim)
+            if new_ef is not None:
+                new_state["ef_error"] = new_ef
         out_metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         for k in ("ce", "aux", "z"):
             if k in metrics:
