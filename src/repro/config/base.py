@@ -107,7 +107,6 @@ class ModelConfig:
     param_dtype: str = "float32"
 
     # implementation switches
-    attention_impl: str = "xla"   # "xla" | "pallas"
     scan_impl: str = "xla"        # ssm/rwkv scan: "xla" | "pallas"
     remat: str = "full"           # "none" | "dots" | "full"
     # layers applied per scan step: the carry stash shrinks by this factor

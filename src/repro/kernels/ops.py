@@ -7,6 +7,7 @@ raises unless its caller asked for interpret mode, e.g. under
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,14 +21,14 @@ from repro.kernels import gmm as _gmm
 @functools.partial(jax.jit, static_argnames=("causal", "q_offset",
                                              "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
-                    block_q: int = 128, block_k: int = 128):
-    """q: [B,Sq,Hq,D]; k,v: [B,Skv,Hkv,D] -> [B,Sq,Hq,D] (model layout)."""
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    o = _fa.flash_attention(qt, kt, vt, causal=causal, q_offset=q_offset,
-                            block_q=block_q, block_k=block_k)
-    return jnp.swapaxes(o, 1, 2)
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
+    """q: [B,Sq,Hq,D]; k,v: [B,Skv,Hkv,D] -> [B,Sq,Hq,D], differentiable.
+
+    Blocks default to the largest of 512/256/128 that divides each
+    length (``flash_attention.pick_block``)."""
+    return _fa.flash_attention(q, k, v, causal=causal, q_offset=q_offset,
+                               block_q=block_q, block_k=block_k)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

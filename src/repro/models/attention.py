@@ -1,11 +1,15 @@
 """GQA attention: init, train/prefill forward (chunked, flash-style), decode.
 
-Two implementations share one module:
-  * ``xla``    – pure jnp, q-block-chunked softmax(QK^T)V.  Fully SPMD
-                 partitionable; this path is what the multi-pod dry-run
-                 lowers (Pallas/Mosaic cannot target the CPU backend).
-  * ``pallas`` – kernels/flash_attention.py via shard_map on real TPU
-                 (validated with interpret=True in tests).
+Two implementations of softmax(QK^T)V share one module:
+  * ``xla``    – pure jnp, q-block-chunked.  Fully SPMD partitionable;
+                 this path is what the multi-pod dry-run lowers
+                 (Pallas/Mosaic cannot target the CPU backend).
+  * ``pallas`` – the fused flash kernel (kernels/flash_attention.py),
+                 forward and backward, called as is: no shard_map, so
+                 only where no multi-device mesh is active.
+``_attend`` takes the kernel where it applies (``_takes_kernel``) and the
+XLA path elsewhere.  Each traced call counts ``attention.flash`` or
+``attention.xla`` in ``repro.obs.counts``.
 
 Weights are stored with FLATTENED head dims ([d_model, H*Dh]) so the tensor
 dims always divide the 16-way model axis even when num_heads doesn't
@@ -18,11 +22,12 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.config.base import ModelConfig
 from repro.models.layers import (
     Params, Axes, dense_init, rmsnorm_init, rmsnorm, apply_rope, apply_mrope,
 )
-from repro.parallel.context import shard
+from repro.parallel.context import current_ctx, shard
 
 ATTN_CHUNK = 2048  # q-block size for the chunked XLA path
 
@@ -169,11 +174,25 @@ def _attend_chunked(cfg: ModelConfig, q: jax.Array, k: jax.Array,
     return out.reshape(B, Sq, Hq, Dh)
 
 
+def _takes_kernel(cfg: ModelConfig, q: jax.Array, k: jax.Array) -> bool:
+    """Whether ``_attend`` runs the fused kernel: where it compiles and
+    nothing must partition it.  The default backend is a TPU, no
+    multi-device mesh is active, the head dim is a multiple of 128 and
+    both lengths tile into blocks of 128."""
+    ctx = current_ctx()
+    return (jax.default_backend() == "tpu"
+            and (ctx is None or ctx.mesh.size == 1)
+            and cfg.head_dim % 128 == 0
+            and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0)
+
+
 def _attend(cfg: ModelConfig, q, k, v, *, causal, q_offset: int = 0):
-    if cfg.attention_impl == "pallas":
+    if _takes_kernel(cfg, q, k):
         from repro.kernels import ops as kops
+        obs.count("attention.flash")
         return kops.flash_attention(q, k, v, causal=causal,
                                     q_offset=q_offset)
+    obs.count("attention.xla")
     return _attend_chunked(cfg, q, k, v, causal=causal, q_offset=q_offset)
 
 

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_scan
 from repro.kernels.rwkv6_scan import rwkv6_scan
@@ -42,10 +43,25 @@ def _compiles_to_mosaic(fn, *args):
 
 
 def test_flash_attention_compiles_at_qwen3_4b_widths(spec):
-    q = spec((1, 32, 4096, 128), jnp.bfloat16)
-    kv = spec((1, 8, 4096, 128), jnp.bfloat16)
+    q = spec((1, 4096, 32, 128), jnp.bfloat16)
+    kv = spec((1, 4096, 8, 128), jnp.bfloat16)
     _compiles_to_mosaic(lambda q, k, v: flash_attention(q, k, v, causal=True),
                         q, kv, kv)
+
+
+def test_flash_attention_backward_compiles_at_qwen3_4b_widths(spec):
+    """Forward and backward (flash_fwd, flash_dq, flash_dkv) through the
+    model's entry point, with the blocks it picks."""
+    q = spec((1, 4096, 32, 128), jnp.bfloat16)
+    kv = spec((1, 4096, 8, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v).astype(jnp.float32))
+
+    fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in text, name
 
 
 def test_rwkv6_scan_compiles_at_rwkv6_3b_widths(spec):
