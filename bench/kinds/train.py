@@ -203,7 +203,7 @@ class Cell:
         """Leaf norms and sketches (:func:`bench.model.sketch`) of
         (weights now - the seed's weights)."""
         cfg, key = self.cfg, _root(self.seed)
-        specs = model.leaf_specs(cfg)
+        stacked = [stack is not None for *_, stack in model.leaf_specs(cfg)]
 
         def f(params, key):
             norms, sketches = [], []
@@ -211,7 +211,7 @@ class Cell:
                 p0 = model.make_leaf(cfg, key, i)
                 d = p.astype(jnp.float32) - p0.astype(jnp.float32)
                 norms.append(jnp.sqrt(jnp.sum(d * d)))
-                if specs[i][0][0] == "blocks":
+                if stacked[i]:
                     sk = jax.vmap(model.sketch)(
                         d, jnp.arange(d.shape[0], dtype=jnp.uint32)).sum(0)
                 else:

@@ -2,80 +2,93 @@
 
 A configuration file (``configs/<name>.json``) holds the published
 ``config.json`` keys of its source, with the keys this benchmark changed
-listed under ``reduced``.  This module turns such a file into the
-program's ``ModelConfig`` and makes weights for it from a seed, in the
-tree layout the program takes (layers stacked on a leading axis), so that
-the program and the plain reference read the same numbers and neither
-makes them.
+listed under ``reduced``.  Its ``model_type`` names the architecture
+module ``archs/<model_type>.py``, which holds everything the benchmark
+knows of that architecture; this module dispatches to it, and makes
+weights from a seed in the tree layout the program takes (each layer
+stack's leaves stacked on a leading axis), so that the program and the
+plain reference read the same numbers and neither makes them.
+
+An architecture module is plain functions and constants:
+
+- ``check(cfg)`` raises where a key holds a value its reference does not
+  implement;
+- ``program_config(cfg)``: the program's ``ModelConfig``;
+- ``stacks(cfg)``: the layer stacks as ``(name, n_layers)``, in the order
+  the layers run;
+- ``leaf_specs(cfg)``: ``(path, shape, init, stack)`` of every weight, in
+  a fixed order, whose index is the leaf's ``fold_in`` index (so the order
+  fixes the weights).  ``init`` is ``normal`` (N(0, initializer_range)),
+  ``ones`` or ``zeros``; ``stack`` names the stack of a stacked leaf
+  (leading axis one per layer) or is ``None`` for a top-level leaf.  The
+  top level holds ``embedding``, ``final_norm`` and, untied, ``unembed``
+  as the last key of their paths; a layer holds its stack's leaves under
+  their paths less the first key;
+- ``layer(cfg, stack, dot, lp, h, pos)``: one layer of the reference in
+  float32 through ``dot`` (:data:`bench.reference.DOTS`), returning the
+  new hidden state, or it and a scalar the loss adds (a router's balance
+  term, say);
+- ``matmul_params(cfg, stack)``: matrix parameters a token meets in one
+  layer of the stack (of experts, the share this chip computes);
+  ``attn_ops(cfg, stack)``: operations per causal query-key pair of such
+  a layer; ``head_params(cfg)``: the output head's (:mod:`bench.flops`);
+- ``TINY``: key overrides that shrink the architecture for the CPU
+  rehearsals, and ``CONTROL``: by the kind of cell, the overrides at
+  which its control test reads the control.
 """
 from __future__ import annotations
 
+import importlib.util
+import os
+import re
+import sys
 from typing import Any, Dict, List, Tuple
 
-from bench.common import root_key
+from bench.common import BENCH_DIR, BenchError, root_key
 
-#: Published keys that must hold these values: the reference implements
-#: only this architecture (Qwen3 dense decoder: GQA with per-head QK-norm,
-#: rotary positions, SwiGLU, RMSNorm).
-_FIXED = {"hidden_act": "silu", "attention_bias": False,
-          "use_sliding_window": False, "rope_scaling": None}
+
+def arch(cfg: Dict[str, Any]):
+    """The architecture module of a configuration, by its ``model_type``."""
+    mt = cfg.get("model_type")
+    if not isinstance(mt, str) or not re.fullmatch(r"[A-Za-z0-9_]+", mt):
+        raise BenchError(f"config {cfg.get('name')}: model_type {mt!r} "
+                         "does not name an architecture module")
+    name = f"bench.archs.{mt}"
+    if name in sys.modules:
+        return sys.modules[name]
+    path = os.path.join(BENCH_DIR, "archs", f"{mt}.py")
+    if not os.path.exists(path):
+        raise BenchError(f"config {cfg.get('name')}: no architecture "
+                         f"module for model_type {mt!r}; add "
+                         f"bench/archs/{mt}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    sys.modules[name] = mod
+    return mod
 
 
 def check_config(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    for k, v in _FIXED.items():
-        if cfg.get(k) != v:
-            raise ValueError(f"config {cfg['name']}: {k}={cfg.get(k)!r}, "
-                             f"the reference implements only {v!r}")
+    arch(cfg).check(cfg)
     return cfg
 
 
 def program_config(cfg: Dict[str, Any]):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.config import DENSE, ModelConfig
-
-    return ModelConfig(
-        name=cfg["name"], family=DENSE,
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        qkv_bias=cfg["attention_bias"], qk_norm=True,
-        rope_theta=float(cfg["rope_theta"]), rms_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        dtype=cfg["torch_dtype"], param_dtype=cfg["torch_dtype"])
+    return arch(cfg).program_config(cfg)
 
 
-def leaf_specs(cfg: Dict[str, Any]) -> List[Tuple[Tuple[str, ...], Tuple[int, ...], str]]:
-    """(path, shape, init) of every weight, in a fixed order.
-
-    ``init`` is ``normal`` (N(0, initializer_range)) or ``ones`` (norm
-    scales), as a freshly initialised Qwen3 has them."""
-    L, D = cfg["num_hidden_layers"], cfg["hidden_size"]
-    H, K, Dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                cfg["head_dim"])
-    F, V = cfg["intermediate_size"], cfg["vocab_size"]
-    specs = [
-        (("embed", "embedding"), (V, D), "normal"),
-        (("final_norm",), (D,), "ones"),
-        (("blocks", "ln1"), (L, D), "ones"),
-        (("blocks", "attn", "wq"), (L, D, H * Dh), "normal"),
-        (("blocks", "attn", "wk"), (L, D, K * Dh), "normal"),
-        (("blocks", "attn", "wv"), (L, D, K * Dh), "normal"),
-        (("blocks", "attn", "wo"), (L, H * Dh, D), "normal"),
-        (("blocks", "attn", "q_norm"), (L, Dh), "ones"),
-        (("blocks", "attn", "k_norm"), (L, Dh), "ones"),
-        (("blocks", "ln2"), (L, D), "ones"),
-        (("blocks", "mlp", "wi_gate"), (L, D, F), "normal"),
-        (("blocks", "mlp", "wi_up"), (L, D, F), "normal"),
-        (("blocks", "mlp", "wo"), (L, F, D), "normal"),
-    ]
-    if not cfg["tie_word_embeddings"]:
-        specs.insert(1, (("embed", "unembed"), (D, V), "normal"))
-    return specs
+Spec = Tuple[Tuple[str, ...], Tuple[int, ...], str, Any]
 
 
-def leaf_name(path: Tuple[str, ...]) -> str:
-    return "/".join(path)
+def leaf_specs(cfg: Dict[str, Any]) -> List[Spec]:
+    """(path, shape, init, stack) of every weight, in a fixed order."""
+    return arch(cfg).leaf_specs(cfg)
+
+
+def layer_stacks(cfg: Dict[str, Any]) -> List[str]:
+    """The stack of each layer, in the order the layers run."""
+    return [s for s, n in arch(cfg).stacks(cfg) for _ in range(n)]
 
 
 def make_leaf(cfg: Dict[str, Any], key, index: int):
@@ -84,10 +97,12 @@ def make_leaf(cfg: Dict[str, Any], key, index: int):
     import jax
     import jax.numpy as jnp
 
-    _, shape, init = leaf_specs(cfg)[index]
+    _, shape, init, _ = leaf_specs(cfg)[index]
     dt = jnp.dtype(cfg["torch_dtype"])
     if init == "ones":
         return jnp.ones(shape, dt)
+    if init == "zeros":
+        return jnp.zeros(shape, dt)
     key = jax.random.fold_in(key, index)
     return (jax.random.normal(key, shape, jnp.float32)
             * cfg["initializer_range"]).astype(dt)
@@ -111,7 +126,7 @@ def make_weights(cfg: Dict[str, Any], seed: int):
 
     def build(key):
         return nest({p: make_leaf(cfg, key, i)
-                     for i, (p, _, _) in enumerate(specs)})
+                     for i, (p, _, _, _) in enumerate(specs)})
 
     return jax.jit(build)(root_key(seed))
 
@@ -148,7 +163,7 @@ def sketch(x, layer=0):
 def flat_leaves(tree: Dict[str, Any], cfg: Dict[str, Any]) -> List[Any]:
     """The leaves of a weight-shaped tree in :func:`leaf_specs` order."""
     out = []
-    for path, _, _ in leaf_specs(cfg):
+    for path, *_ in leaf_specs(cfg):
         node = tree
         for k in path:
             node = node[k]

@@ -1,12 +1,15 @@
-"""The plain reference: a Qwen3 dense decoder and two AdamW steps.
+"""The plain reference: a decoder's forward and backward, and two AdamW
+steps.
 
-Written from the published architecture (Qwen3ForCausalLM: RMSNorm,
-grouped-query attention with per-head RMSNorm on queries and keys, rotary
-positions with the rotate-half convention, SwiGLU, tied or untied head)
-and from the optimiser settings in a cell's traffic file.  It imports
-nothing of the program and takes no array the program made: the weights
-come from :mod:`bench.model` and the seed, the token stream from
-:mod:`bench.corpus`.
+The layers are the architecture module's (``bench/archs/<model_type>.py``,
+written from the published architecture, through :func:`bench.model.arch`);
+this module holds what every decoder shares: the matrix products in each
+precision, RMSNorm, rotary positions and causal attention for the layers
+to use, the embedding, the tied or untied head with its loss, the pass
+over the layer stacks, and the optimiser as a cell's traffic file sets
+it.  It imports nothing of the program and takes no array the program
+made: the weights come from :mod:`bench.model` and the seed, the token
+stream from :mod:`bench.corpus`.
 
 Arithmetic is float32 with ``Precision.HIGHEST`` matrix products.  The
 ``fp8`` variant rounds both operands of every matrix product to
@@ -15,7 +18,9 @@ put one precision below the bfloat16 the configurations state.
 
 Training is layer by layer (a forward pass that keeps each layer's input,
 then one ``vjp`` per layer), and the loss head runs in blocks of rows, so
-that a whole step at seq 4096 fits one chip.  Two departures from the
+that a whole step at seq 4096 fits one chip.  A layer may return a term
+the loss adds (a router's balance loss); its gradient flows back through
+that layer's ``vjp``.  Two departures from the
 published description are the program's objective, followed here on
 purpose: the loss adds ``1e-4 * mean(logsumexp**2)`` (a z-loss), and the
 learning rate follows a linear warm-up from 0 and a cosine decay to a
@@ -53,13 +58,13 @@ def _round8(x, dtype):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _dot_fp8(eq: str, a, b):
-    return _dot_f32(eq, _round8(_f32(a), jnp.float8_e4m3fn),
-                    _round8(_f32(b), jnp.float8_e4m3fn))
+    return _dot_f32(eq, _round8(f32(a), jnp.float8_e4m3fn),
+                    _round8(f32(b), jnp.float8_e4m3fn))
 
 
 def _dot_fp8_fwd(eq, a, b):
-    ra = _round8(_f32(a), jnp.float8_e4m3fn)
-    rb = _round8(_f32(b), jnp.float8_e4m3fn)
+    ra = _round8(f32(a), jnp.float8_e4m3fn)
+    rb = _round8(f32(b), jnp.float8_e4m3fn)
     return _dot_f32(eq, ra, rb), (ra, rb)
 
 
@@ -77,12 +82,35 @@ _dot_fp8.defvjp(_dot_fp8_fwd, _dot_fp8_bwd)
 DOTS: Dict[str, Callable] = {"f32": _dot_f32, "fp8": _dot_fp8}
 
 
-def _f32(x):
+def f32(x):
     return x.astype(jnp.float32)
 
 
 def rmsnorm(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * _f32(w)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * f32(w)
+
+
+def attend(dot, q, k, v):
+    """Causal softmax attention in blocks of query rows; q [B,S,H,Dq],
+    k [B,S,K,Dq] and v [B,S,K,Dv] with K dividing H (grouped queries)."""
+    B, S, H, Dh = q.shape
+    K = k.shape[2]
+    G = H // K
+    q = q.reshape(B, S, K, G, Dh)
+    outs = []
+    kpos = jnp.arange(S)
+
+    @jax.checkpoint
+    def block(qb, start):
+        s = dot("bqkgd,bskd->bkgqs", qb, k) * Dh ** -0.5
+        qpos = start + jnp.arange(qb.shape[1])
+        s = jnp.where(qpos[:, None] >= kpos[None, :], s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        return dot("bkgqs,bskd->bqkgd", w, v)
+
+    for start in range(0, S, ATTN_BLOCK):
+        outs.append(block(q[:, start:start + ATTN_BLOCK], start))
+    return jnp.concatenate(outs, 1).reshape(B, S, H * v.shape[-1])
 
 
 def rope(x, pos, theta):
@@ -100,10 +128,12 @@ class Reference:
 
     def __init__(self, cfg: Dict[str, Any], precision: str = "f32"):
         self.cfg = cfg
+        self.arch = model.arch(cfg)
+        self.order = model.layer_stacks(cfg)
         self.dot = DOTS[precision]
         self.eps = cfg["rms_norm_eps"]
-        self.fwd_layer = jax.jit(self._layer)
-        self.bwd_layer = jax.jit(self._layer_vjp)
+        self.fwd_layer = jax.jit(self._layer, static_argnums=(0,))
+        self.bwd_layer = jax.jit(self._layer_vjp, static_argnums=(0,))
         self.head_grad = jax.jit(self._head_grad, donate_argnums=(0, 1, 2))
         self.head_loss = jax.jit(self._head_loss)
         self.embed_grad = jax.jit(
@@ -112,55 +142,20 @@ class Reference:
         self.logits = jax.jit(self._logits)
 
     # ---- one layer -------------------------------------------------------
-    def _attend(self, q, k, v):
-        """Causal softmax attention; q [B,S,H,Dh], k/v [B,S,K,Dh]."""
-        B, S, H, Dh = q.shape
-        K = k.shape[2]
-        G = H // K
-        q = q.reshape(B, S, K, G, Dh)
-        outs = []
-        kpos = jnp.arange(S)
+    def _layer(self, stack, lp, h, pos):
+        return self.arch.layer(self.cfg, stack, self.dot, lp, h, pos)
 
-        @jax.checkpoint
-        def block(qb, start):
-            s = self.dot("bqkgd,bskd->bkgqs", qb, k) * Dh ** -0.5
-            qpos = start + jnp.arange(qb.shape[1])
-            s = jnp.where(qpos[:, None] >= kpos[None, :], s, -jnp.inf)
-            w = jax.nn.softmax(s, axis=-1)
-            return self.dot("bkgqs,bskd->bqkgd", w, v)
-
-        for start in range(0, S, ATTN_BLOCK):
-            outs.append(block(q[:, start:start + ATTN_BLOCK], start))
-        return jnp.concatenate(outs, 1).reshape(B, S, H * Dh)
-
-    def _layer(self, lp, h, pos):
-        c, dot, eps = self.cfg, self.dot, self.eps
-        B, S, _ = h.shape
-        H, K, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                    c["head_dim"])
-        x = rmsnorm(h, lp["ln1"], eps)
-        a = lp["attn"]
-        q = dot("bsd,dh->bsh", x, _f32(a["wq"])).reshape(B, S, H, Dh)
-        k = dot("bsd,dh->bsh", x, _f32(a["wk"])).reshape(B, S, K, Dh)
-        v = dot("bsd,dh->bsh", x, _f32(a["wv"])).reshape(B, S, K, Dh)
-        q = rope(rmsnorm(q, a["q_norm"], eps), pos, c["rope_theta"])
-        k = rope(rmsnorm(k, a["k_norm"], eps), pos, c["rope_theta"])
-        h = h + dot("bsh,hd->bsd", self._attend(q, k, v), _f32(a["wo"]))
-        x = rmsnorm(h, lp["ln2"], eps)
-        m = lp["mlp"]
-        g = dot("bsd,df->bsf", x, _f32(m["wi_gate"]))
-        u = dot("bsd,df->bsf", x, _f32(m["wi_up"]))
-        return h + dot("bsf,fd->bsd", jax.nn.silu(g) * u, _f32(m["wo"]))
-
-    def _layer_vjp(self, lp, h, pos, dh):
-        _, pull = jax.vjp(lambda p, x: self._layer(p, x, pos), lp, h)
+    def _layer_vjp(self, stack, lp, h, pos, dh):
+        out, pull = jax.vjp(lambda p, x: self._layer(stack, p, x, pos), lp, h)
+        if isinstance(out, tuple):       # the layer's loss term, added once
+            return pull((dh, jnp.ones_like(out[1])))
         return pull(dh)
 
     # ---- head and loss ---------------------------------------------------
     def _head_w(self, top):
         if "unembed" in top:
-            return _f32(top["unembed"]), "bsd,dv->bsv"
-        return _f32(top["embedding"]), "bsd,vd->bsv"
+            return f32(top["unembed"]), "bsd,dv->bsv"
+        return f32(top["embedding"]), "bsd,vd->bsv"
 
     def _rows_loss(self, top, h, tgt, mask, denom):
         """This block's share of the loss: sum over its rows / denom."""
@@ -188,22 +183,27 @@ class Reference:
 
     # ---- whole passes ----------------------------------------------------
     def embed(self, top, tokens):
-        return _f32(jnp.take(top["embedding"], tokens, axis=0))
+        return f32(jnp.take(top["embedding"], tokens, axis=0))
 
     def forward_hidden(self, top, layers, tokens, pos):
+        """The hidden state entering each layer and leaving the last, and
+        the sum of the layers' loss terms."""
         h = self.embed(top, tokens)
-        hs = [h]
-        for lp in layers:
-            h = self.fwd_layer(lp, h, pos)
+        hs, extra = [h], 0.0
+        for stack, lp in zip(self.order, layers):
+            h = self.fwd_layer(stack, lp, h, pos)
+            if isinstance(h, tuple):
+                h, term = h
+                extra += float(term)
             hs.append(h)
-        return hs
+        return hs, extra
 
     def loss(self, top, layers, batch) -> float:
         tokens, tgt, mask = batch
         pos = _positions(tokens)
-        h = self.forward_hidden(top, layers, tokens, pos)[-1]
+        hs, total = self.forward_hidden(top, layers, tokens, pos)
+        h = hs[-1]
         denom = jnp.maximum(jnp.sum(mask), 1.0)
-        total = 0.0
         for s in range(0, tokens.shape[1], HEAD_BLOCK):
             sl = slice(s, s + HEAD_BLOCK)
             total += float(self.head_loss(top, h[:, sl], tgt[:, sl],
@@ -216,7 +216,7 @@ class Reference:
         (loss, gradients of the top-level weights)."""
         tokens, tgt, mask = batch
         pos = _positions(tokens)
-        hs = self.forward_hidden(top, layers, tokens, pos)
+        hs, extra = self.forward_hidden(top, layers, tokens, pos)
         denom = jnp.maximum(jnp.sum(mask), 1.0)
         key = "unembed" if "unembed" in top else "embedding"
         acc_w = jnp.zeros(top[key].shape, jnp.float32)
@@ -231,7 +231,8 @@ class Reference:
             dhs.append(dh)
         dh = jnp.concatenate(dhs, 1)
         for i in reversed(range(len(layers))):
-            dlp, dh = self.bwd_layer(layers[i], hs[i], pos, dh)
+            dlp, dh = self.bwd_layer(self.order[i], layers[i], hs[i], pos,
+                                     dh)
             hs[i + 1] = None
             on_layer(i, dlp)
         g_top = {"final_norm": acc_n}
@@ -239,7 +240,7 @@ class Reference:
             g_top["unembed"] = acc_w
             acc_w = jnp.zeros(top["embedding"].shape, jnp.float32)
         g_top["embedding"] = self.embed_grad(acc_w, tokens, dh)
-        return float(acc_l), g_top
+        return float(acc_l) + extra, g_top
 
 
 def _positions(tokens):
@@ -248,26 +249,32 @@ def _positions(tokens):
 
 
 # ---------------------------------------------------------------------------
-# weights in the reference's layout: top-level leaves + one dict per layer
+# weights in the reference's layout: top-level leaves + one dict per layer,
+# the layers of every stack in the order they run
 # ---------------------------------------------------------------------------
 
 def split_layers(cfg, flat: List[Any]):
     """Flat leaves (``model.leaf_specs`` order, layers stacked) -> (top,
     layers).  Each stacked leaf is freed once it is cut into layers."""
+    order = model.layer_stacks(cfg)
     top: Dict[str, Any] = {}
-    layers: List[Dict[str, Any]] = [dict() for _ in
-                                    range(cfg["num_hidden_layers"])]
-    for (path, _, _), leaf in zip(model.leaf_specs(cfg), flat):
-        if path[0] != "blocks":
+    layers: List[Dict[str, Any]] = [dict() for _ in order]
+    for (path, _, _, stack), leaf in zip(model.leaf_specs(cfg), flat):
+        if stack is None:
             top[path[-1]] = leaf
             continue
-        for i, lp in enumerate(layers):
+        for i, lp in enumerate(_of_stack(order, layers, stack)):
             node = lp
             for k in path[1:-1]:
                 node = node.setdefault(k, {})
             node[path[-1]] = leaf[i]
         leaf.delete()
     return top, layers
+
+
+def _of_stack(order: List[str], layers: List[Any], stack: str) -> List[Any]:
+    """The layers of one stack, in order."""
+    return [lp for s, lp in zip(order, layers) if s == stack]
 
 
 def _get(tree, path):
@@ -278,17 +285,19 @@ def _get(tree, path):
 
 def leaf_norms(cfg, top, layers) -> List[float]:
     """Norm of each leaf of a reference-layout tree, ``leaf_specs`` order."""
+    order = model.layer_stacks(cfg)
     out = []
-    for path, _, _ in model.leaf_specs(cfg):
-        if path[0] != "blocks":
+    for path, _, _, stack in model.leaf_specs(cfg):
+        if stack is None:
             out.append(float(_norm(top[path[-1]])))
         else:
             out.append(math.sqrt(sum(float(_norm(_get(lp, path[1:]))) ** 2
-                                     for lp in layers)))
+                                     for lp in _of_stack(order, layers,
+                                                         stack))))
     return out
 
 
-_norm = jax.jit(lambda x: jnp.sqrt(jnp.sum(jnp.square(_f32(x)))))
+_norm = jax.jit(lambda x: jnp.sqrt(jnp.sum(jnp.square(f32(x)))))
 
 
 # ---------------------------------------------------------------------------
@@ -324,13 +333,12 @@ def _adam(p, g1, g2, c1, c2, lr, wd, *, t, b1, b2, eps):
         m = b1 * m + (1 - b1) * g2
         v = b2 * v + (1 - b2) * g2 * g2
     u = (m / (1 - b1 ** t)) / (jnp.sqrt(v / (1 - b2 ** t)) + eps)
-    pf = _f32(p)
+    pf = f32(p)
     return (pf - lr * (u + wd * pf)).astype(p.dtype)
 
 
-def _wd(opt, path, shape) -> float:
-    """Weight decay on matrices only, by the shape of one layer's leaf."""
-    ndim = len(shape) - (1 if path[0] == "blocks" else 0)
+def _wd(opt, ndim: int) -> float:
+    """Weight decay on matrices only, by the rank of one layer's leaf."""
     return opt["weight_decay"] if ndim >= 2 else 0.0
 
 
@@ -359,13 +367,12 @@ def train_reference(cfg: Dict[str, Any], seed: int, batches, opt: Dict,
     grad_norms = [c1 * n for n in leaf_norms(cfg, g1_top, g1_layers)]
     lr1 = lr_at(0, opt)
     top = {k: _adam(v, g1_top[k], g1_top[k], c1, 0.0, lr1,
-                    _wd(opt, (k,), v.shape), t=1, **kw)
+                    _wd(opt, v.ndim), t=1, **kw)
            for k, v in top.items()}
     for i, lp in enumerate(layers):
-        layers[i] = _map_layer(lp, g1_layers[i], None, lambda p, a, b, path:
-                               _adam(p, a, a, c1, 0.0, lr1,
-                                     _wd(opt, ("blocks",) + path,
-                                         (1,) + p.shape), t=1, **kw))
+        layers[i] = _map_layer(lp, g1_layers[i], None, lambda p, a, b:
+                               _adam(p, a, a, c1, 0.0, lr1, _wd(opt, p.ndim),
+                                     t=1, **kw))
 
     # update 2: one pass for the clipping norm, one that applies it ---------
     sq = [0.0]
@@ -382,15 +389,14 @@ def train_reference(cfg: Dict[str, Any], seed: int, batches, opt: Dict,
 
     def apply(i, g):
         new_layers[i] = _map_layer(
-            layers[i], g1_layers[i], g, lambda p, a, b, path: _adam(
-                p, a, b, c1, c2, lr2, _wd(opt, ("blocks",) + path,
-                                          (1,) + p.shape), t=2, **kw))
+            layers[i], g1_layers[i], g, lambda p, a, b: _adam(
+                p, a, b, c1, c2, lr2, _wd(opt, p.ndim), t=2, **kw))
         g1_layers[i] = None
 
     ref.grads(top, layers, batches[1], apply)
     layers = new_layers
     top = {k: _adam(v, g1_top[k], g2_top[k], c1, c2, lr2,
-                    _wd(opt, (k,), v.shape), t=2, **kw)
+                    _wd(opt, v.ndim), t=2, **kw)
            for k, v in top.items()}
     del g1_top, g2_top
 
@@ -400,15 +406,16 @@ def train_reference(cfg: Dict[str, Any], seed: int, batches, opt: Dict,
     # its norm and its sketch (bench.model.sketch, summed over layers)
     key = _root(seed)
     delta, sketches = [], []
-    for idx, (path, _, _) in enumerate(specs):
+    for idx, (path, _, _, stack) in enumerate(specs):
         p0 = jax.jit(lambda k: model.make_leaf(cfg, k, idx))(key)
-        if path[0] != "blocks":
+        if stack is None:
             n, sk = _change(top[path[-1]], p0, 0)
             delta.append(float(n))
             sketches.append(np.asarray(sk))
         else:
             parts = [_change(_get(lp, path[1:]), p0[i], i)
-                     for i, lp in enumerate(layers)]
+                     for i, lp in enumerate(_of_stack(ref.order, layers,
+                                                      stack))]
             delta.append(math.sqrt(sum(float(n) ** 2 for n, _ in parts)))
             sketches.append(np.sum([np.asarray(sk) for _, sk in parts], 0))
         p0.delete()
@@ -418,7 +425,7 @@ def train_reference(cfg: Dict[str, Any], seed: int, batches, opt: Dict,
 
 @jax.jit
 def _change(p, p0, layer):
-    d = _f32(p) - _f32(p0)
+    d = f32(p) - f32(p0)
     return jnp.sqrt(jnp.sum(d * d)), model.sketch(d, layer)
 
 
@@ -435,11 +442,11 @@ def _mask_rows(batch, rows):
     return tokens, tgt, mask
 
 
-def _map_layer(lp, ga, gb, fn, path=()):
+def _map_layer(lp, ga, gb, fn):
     if isinstance(lp, dict):
         return {k: _map_layer(lp[k], ga[k], None if gb is None else gb[k],
-                              fn, path + (k,)) for k in lp}
-    return fn(lp, ga, gb if gb is not None else ga, path)
+                              fn) for k in lp}
+    return fn(lp, ga, gb if gb is not None else ga)
 
 
 def _global_norm(top, layers) -> float:
@@ -457,5 +464,5 @@ def sequence_logits(ref: Reference, top, layers, tokens: np.ndarray):
     """Logits [S, V] (float32) of one sequence under the reference."""
     t = jnp.asarray(tokens, jnp.int32)[None]
     pos = _positions(t)
-    h = ref.forward_hidden(top, layers, t, pos)[-1]
+    h = ref.forward_hidden(top, layers, t, pos)[0][-1]
     return ref.logits(top, h)[0]

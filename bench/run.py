@@ -3,11 +3,16 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is looked up by name in ``BENCHMARK.json`` at the checkout's
-root.  Its configuration is ``bench/configs/<config>.json``, its traffic
-``bench/traffic/<traffic>.json``, whose ``kind`` names the driver in
-``bench/kinds/``, and its limits ``bench/limits/<workload>.json``.  Each
-metric is read by ``bench/metrics/<metric>.py``.  Adding a cell or a
-metric adds such files and entries, and edits none.
+root.  Its configuration is ``bench/configs/<config>.json``, whose
+``model_type`` names the architecture module ``bench/archs/<model_type>.py``,
+its traffic ``bench/traffic/<traffic>.json``, whose ``kind`` names the
+module of ``bench/kinds/`` that runs it, and its limits
+``bench/limits/<workload>.json``.
+Each metric is read by ``bench/metrics/<metric>.py``.  Adding a cell, a
+metric or an architecture adds such files and entries, and edits none,
+as long as the program trains or serves the architecture through the
+entry points a kind drives (``Trainer``, ``ServeEngine``); a new kind of
+traffic is new code in ``bench/kinds/``.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from spans, counters and a

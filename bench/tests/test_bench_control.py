@@ -10,30 +10,17 @@ from bench import run
 from bench.tests import tiny
 
 
-# At the tiny widths a random model's best logit stands far above the
-# rest, and float8 still picks it; at these widths its gaps are as the
-# served model's are (PERF.md), so the serving control is read here.
-SERVE_CONFIG = dict(tiny.CONFIG, hidden_size=1024, num_attention_heads=8,
-                    num_key_value_heads=2, head_dim=128,
-                    intermediate_size=2048, vocab_size=32768)
-# At the tiny widths float8 moves a step's loss and its update less than it
-# does at the cells' own sizes (PERF.md), so the training control is read
-# at these widths, where it separates from the program as on the chip.
-TRAIN_CONFIG = dict(tiny.CONFIG, hidden_size=256, head_dim=64,
-                    intermediate_size=512, vocab_size=4096)
-
-
 @pytest.mark.parametrize("workload", [w["name"] for w in
                                       tiny.spec()["workloads"]])
 def test_control_is_not_correct(workload):
     o = tiny.overrides(workload)
+    kind = tiny.traffic(workload)["kind"]
+    # the widths at which the control separates as at the cell's own size
+    o["config"] = dict(tiny.arch(workload).CONTROL[kind])
     seconds = 1.0
-    if tiny.traffic(workload)["kind"] == "serve":
-        o["config"] = SERVE_CONFIG
+    if kind == "serve":
         o["traffic"].update(requests=16, check_tokens=60)
         seconds = 4.0
-    else:
-        o["config"] = TRAIN_CONFIG
     out = run.measure(workload, tiny.SEED, seconds, False, need_tpu=False,
                       overrides=o, controls=True, spec=tiny.spec())
     limits = {k: c["limit"] for k, c in out["checks"].items()}

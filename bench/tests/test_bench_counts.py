@@ -5,12 +5,12 @@ import pytest
 
 from bench import flops, peaks
 
-QWEN3_4B = {"hidden_size": 2560, "intermediate_size": 9728,
-            "num_attention_heads": 32, "num_key_value_heads": 8,
-            "head_dim": 128, "vocab_size": 151936}
-QWEN3_8B = {"hidden_size": 4096, "intermediate_size": 12288,
-            "num_attention_heads": 32, "num_key_value_heads": 8,
-            "head_dim": 128, "vocab_size": 151936}
+QWEN3_4B = {"model_type": "qwen3", "hidden_size": 2560,
+            "intermediate_size": 9728, "num_attention_heads": 32,
+            "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936}
+QWEN3_8B = {"model_type": "qwen3", "hidden_size": 4096,
+            "intermediate_size": 12288, "num_attention_heads": 32,
+            "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936}
 
 
 @pytest.mark.parametrize("widths,layer_params", [

@@ -1,16 +1,19 @@
-"""Tiny sizes for rehearsing cells on the CPU: every width cut, the
+"""Tiny sizes for rehearsing cells on the CPU: every width cut (by the
+``TINY`` overrides of the configuration's architecture module), the
 traffic shortened, so that a whole run takes seconds."""
 from __future__ import annotations
 
 import json
 import os
 
+from bench import model
+from bench.archs import qwen3
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-CONFIG = {"hidden_size": 64, "num_attention_heads": 4,
-          "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
-          "vocab_size": 512, "num_hidden_layers": 2}
+#: Qwen3's overrides, for the tests that shrink a Qwen3 file by hand.
+CONFIG = qwen3.TINY
 
 TRAFFIC = {
     "train": {"seq": 128, "corpus": {"shards": 4, "shard_tokens": 4096}},
@@ -47,12 +50,24 @@ def traffic(workload: str):
         return json.load(f)
 
 
+def config(workload: str):
+    w = next(w for w in spec()["workloads"] if w["name"] == workload)
+    with open(os.path.join(ROOT, "bench", "configs",
+                           f"{w['config']}.json")) as f:
+        return json.load(f)
+
+
+def arch(workload: str):
+    """The architecture module of a cell's configuration."""
+    return model.arch(config(workload))
+
+
 def overrides(workload: str):
     mix = traffic(workload)
     t = dict(TRAFFIC[mix["kind"]])
     if mix.get("ckpt_every"):
         t["ckpt_every"] = 6        # saves fall in a short window
-    return {"config": CONFIG, "traffic": t}
+    return {"config": dict(arch(workload).TINY), "traffic": t}
 
 
 def workloads(kind: str):
